@@ -907,7 +907,6 @@ def bench_width_split_band() -> None:
     bit-equal.  Ratio-gated split/unsplit (same process, same bounds)."""
 
     import jax
-    import jax.numpy as jnp
 
     from repro.core import analyze, insert_synchronization
     from repro.core.wavefront import _DenseStore
@@ -933,27 +932,8 @@ def bench_width_split_band() -> None:
             case, _ = comp.prepare(sync.program, dense)
         finally:
             lowering.WIDTH_LADDER_RUNGS = saved
-        with jax.experimental.enable_x64():
-            dstore = {
-                a: jnp.zeros((case.padded_sizes[a],), jnp.float64)
-                .at[: case.flat_sizes[a]]
-                .set(jnp.asarray(dense.data[a].ravel()))
-                for a in case.arrays
-            }
-            cov = {
-                a: jnp.zeros((case.padded_sizes[a],), bool)
-                for a in case.sparse
-            }
-            args = (
-                case.static,
-                jnp.int64(case.n_levels),
-                tuple(jnp.asarray(d) for d in case.seg_dyn),
-                comp._to_device(case),
-                dstore,
-                cov,
-                jnp.zeros((2,), bool),
-                jnp.int64(0),
-            )
+        with lowering.x64():
+            args = (case.static, *comp.device_args(case, dense))
             jax.block_until_ready(comp._jit(*args))  # warm the trace
             best = float("inf")
             for _ in range(reps):
@@ -1368,6 +1348,9 @@ def main(argv: List[str] | None = None) -> None:
     )
     args = ap.parse_args(argv)
 
+    from repro.compile.lowering import use_persistent_compile_cache
+
+    use_persistent_compile_cache()
     calib_payload = None
     if args.calibrate:
         import repro.calibrate as calibrate

@@ -145,23 +145,16 @@ def enabled() -> bool:
 
 def host_info() -> Dict[str, str]:
     """The identity a profile is keyed by: platform, accelerator backend,
-    device count and jax version (``nojax`` placeholders when jax is
-    absent, so the fingerprint is still stable)."""
+    device count and jax version."""
 
-    try:
-        import jax
+    import jax
 
-        backend = jax.default_backend()
-        devices = str(jax.local_device_count())
-        version = str(jax.__version__)
-    except Exception:  # pragma: no cover - jax is baked into the image
-        backend, devices, version = "nojax", "0", "0"
     return {
         "machine": _platform.machine(),
         "system": _platform.system(),
-        "backend": backend,
-        "devices": devices,
-        "jax": version,
+        "backend": jax.default_backend(),
+        "devices": str(jax.local_device_count()),
+        "jax": str(jax.__version__),
     }
 
 

@@ -31,7 +31,7 @@ re-measures nothing.
 from __future__ import annotations
 
 import time
-from typing import Dict, Optional, Tuple
+from typing import Dict, Tuple
 
 from repro.obs import metrics as _metrics
 
@@ -95,11 +95,9 @@ def _jit_band_seconds(cache, n: int, dist: int, repeats: int) -> Tuple[
     pre-packed device buffers."""
 
     import jax
-    import jax.numpy as jnp
-    import numpy as np
-    from jax.experimental import enable_x64
 
     from repro.compile.executor import run_xla
+    from repro.compile.lowering import x64
     from repro.core.wavefront import _DenseStore
 
     prog = _chain_program(n, dist)
@@ -115,26 +113,11 @@ def _jit_band_seconds(cache, n: int, dist: int, repeats: int) -> Tuple[
     compiled = rep.compiled
     dense = _DenseStore({a: dict(c) for a, c in init.items()})
     case, _ = compiled.prepare(prog, dense)
-    with enable_x64():
-        store = {}
-        for a in case.arrays:
-            flat = np.zeros(case.padded_sizes[a], dtype=np.float64)
-            flat[: case.flat_sizes[a]] = dense.data[a].ravel()
-            store[a] = jnp.asarray(flat)
-        coverage = {}  # chain programs have no sparse arrays
+    with x64():
+        args = compiled.device_args(case, dense)
 
         def call():
-            out_store, _, bad = compiled._jit(
-                case.static,
-                case.n_levels,
-                case._device_segdyn,
-                case._device_tables,
-                store,
-                coverage,
-                jnp.zeros((2,), bool),
-                jnp.int64(0),
-            )
-            jax.block_until_ready((out_store, bad))
+            jax.block_until_ready(compiled._jit(case.static, *args))
 
         call()  # warm this exact shape (same bucket — no re-trace)
         best = _best_of(call, repeats)
@@ -168,16 +151,18 @@ def measure_units(
     n: int = 8192,
     widths: Tuple[int, ...] = (8, 64, 512),
     repeats: int = 3,
-    spmd: Optional[bool] = None,
+    spmd: bool = False,
 ) -> Tuple[Dict[str, float], dict]:
     """Run the suite; returns ``(units, meta)`` for a fresh CostProfile.
 
     ``widths`` must be powers of two (each is a carried distance = chunk
     width = padded lane count); ``n`` the largest chain length (the small
-    size is ``n // 2``).  ``spmd=None`` measures collectives only when the
-    host actually has ≥ 2 devices, else scales the hand-set collective
-    ratios by the measured per-lane cost so the profile stays on one unit
-    scale.
+    size is ``n // 2``).  Everything runs on the default device unless
+    ``spmd=True``, which also measures the collectives over every local
+    device (on a one-device host that stays a no-op); otherwise the
+    hand-set collective ratios are scaled by the measured per-lane cost so
+    the profile stays on one unit scale.  A serving process that owns one
+    chip of a multi-chip host therefore never touches the others.
     """
 
     from repro.compile.cache import CompileCache
@@ -218,14 +203,11 @@ def measure_units(
 
     # -- spmd band step: collective flat + per gathered lane ------------ #
     n_dev = 1
-    if spmd is not False:
-        try:
-            import jax
+    if spmd:
+        import jax
 
-            n_dev = _pow2_floor(jax.local_device_count())
-        except Exception:  # pragma: no cover - jax is baked into the image
-            n_dev = 1
-    if spmd is True or (spmd is None and n_dev >= 2):
+        n_dev = _pow2_floor(jax.local_device_count())
+    if n_dev >= 2:
         from repro.compile.spmd import SpmdCompiledProgram
 
         spmd_cache = CompileCache(factory=SpmdCompiledProgram)
@@ -249,13 +231,13 @@ def measure_units(
         meta["spmd_delta_us"] = {str(w): d for w, d in deltas}
         meta["spmd_devices"] = n_dev
     else:
-        # single-device host: keep the hand-set collective *ratios* (they
-        # are expressed in lane units) on the measured lane scale
+        # one device: keep the hand-set collective *ratios* (they are
+        # expressed in lane units) on the measured lane scale
         import repro.compile.spmd as _spmd
 
         spmd_collective = _spmd.SPMD_COLLECTIVE_UNITS * xla_lane
         spmd_collective_lane = _spmd.SPMD_COLLECTIVE_LANE_UNITS * xla_lane
-        meta["spmd_delta_us"] = "skipped (single-device host)"
+        meta["spmd_delta_us"] = "skipped (one device)"
         meta["spmd_devices"] = n_dev
 
     # -- interpreter dispatch: per batched group of the NumPy wavefront - #

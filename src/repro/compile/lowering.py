@@ -55,9 +55,11 @@ vectors (``PreparedCase.seg_dyn``), so hybrid artifacts bucket-share traces
 exactly like acyclic ones.  Schedules without recurrence SCCs take a single
 level loop over a traced level count.
 
-Everything runs in ``float64`` (via :func:`jax.experimental.enable_x64`), so
-stores are bit-equal to :func:`repro.core.ir.run_sequential` — the same
-contract the other executors are held to by ``tests/oracle.py``.
+Everything runs in ``float64`` (inside :func:`x64`), so on XLA:CPU stores
+are bit-equal to :func:`repro.core.ir.run_sequential` — the same contract
+the other executors are held to by ``tests/oracle.py``.  A TPU has no
+float64 hardware: XLA:TPU emulates it, and there the contract is
+:data:`TPU_F64_RTOL` (see :func:`_protect`).
 
 Error parity with the NumPy backend: an access outside the initialized store
 raises ``KeyError("… outside the initialized store …")`` (statically for
@@ -109,6 +111,47 @@ WIDTH_LADDER_RUNGS = 3
 WIDTH_LADDER_MIN = 8
 
 
+# The chip's contract.  XLA:TPU emulates float64 with pairs of float32: on a
+# v5e a host→device→host round trip moves values by up to 8 ulps, a single
+# mul/div is off by up to 2^-44 relative to its operands, and magnitudes
+# beyond float32's range (~3.4e38) overflow.  So on a TPU a store is held
+# to ``run_sequential`` normwise per array, max|got - want| ≤ TPU_F64_RTOL ·
+# max|want|, with 2^-32 = 2^-44 per operation over dependence chains of up
+# to 2^12 operations.  XLA:CPU stays bit-equal.
+TPU_F64_RTOL = 2.0**-32
+
+
+def x64():
+    """The float64 scope every level-loop execution runs in (a context
+    manager)."""
+
+    import jax
+
+    return jax.enable_x64(True)
+
+
+def use_persistent_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at a fixed directory and
+    return it.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is used as JAX reads it and
+    nothing is set in code; otherwise ``<checkout>/.jax_cache``.  The path
+    is fixed, never built from a temporary name, a process id or the time,
+    so a later process on the same checkout finds what an earlier one
+    compiled."""
+
+    import os
+    from pathlib import Path
+
+    import jax
+
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(Path(__file__).resolve().parents[3] / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 # ---------------------------------------------------------------------- #
 # Strict lane arithmetic.  XLA's CPU emitter compiles the whole computation
 # into one LLVM function with aggressive FP op fusion, so a multiply feeding
@@ -125,6 +168,7 @@ WIDTH_LADDER_MIN = 8
 # producer→consumer float pattern, forcing each IEEE op to round
 # individually exactly like the sequential oracle.  Cost: two bitcasts and
 # an integer xor per op per lane, on expressions a handful of ops long.
+# Only an XLA:CPU executable carries it (see ``_protect``).
 # ---------------------------------------------------------------------- #
 
 class _StrictLane:
@@ -157,15 +201,31 @@ def _unwrap(v):
     return v.x if isinstance(v, _StrictLane) else v
 
 
+def _launder(x, z):
+    import jax.numpy as jnp
+    from jax import lax
+
+    bits = lax.bitcast_convert_type(x, jnp.int64)
+    return lax.bitcast_convert_type(jnp.bitwise_xor(bits, z), jnp.float64)
+
+
 def _protect(x, z):
+    """Launder ``x`` on XLA:CPU; pass it through everywhere else.
+
+    The form is chosen per lowering platform (``lax.platform_dependent``
+    keeps only the branch of the platform the executable is compiled for).
+    XLA:TPU emulates float64 and its rewrite has no rule for a 64-bit
+    bitcast, so the TPU executable carries no laundering."""
+
     import jax.numpy as jnp
     from jax import lax
 
     x = jnp.asarray(x)
     if x.dtype != jnp.float64:  # int/bool intermediates are already exact
         return x
-    bits = lax.bitcast_convert_type(x, jnp.int64)
-    return lax.bitcast_convert_type(jnp.bitwise_xor(bits, z), jnp.float64)
+    return lax.platform_dependent(
+        x, z, cpu=_launder, default=lambda x, z: x
+    )
 
 
 def _launder_operand(v, z):
@@ -481,7 +541,9 @@ class CompiledProgram:
                     return out
                 if out.ndim == 0:
                     return jnp.broadcast_to(out, (width,))
-            except Exception:
+            except TypeError:
+                # the compute fn does not speak the proxy protocol (it
+                # branched on a lane, or handed a proxy to jnp directly)
                 pass
             try:
                 return jnp.asarray(jax.vmap(stmt.compute)(*reads), jnp.float64)
@@ -1207,27 +1269,55 @@ class CompiledProgram:
     # Host-side execution wrapper
     # ------------------------------------------------------------------ #
 
-    @staticmethod
-    def _to_device(case: PreparedCase) -> Tuple:
+    def device_args(self, case: PreparedCase, dense: _DenseStore) -> Tuple:
+        """The arguments of ``self._jit`` after ``case.static`` that run
+        ``case`` on ``dense``: segment scalars and level tables (converted
+        once per case), the padded store and coverage buffers, the error
+        flags and the opaque zero.  Call inside :func:`x64`."""
+
         import jax.numpy as jnp
 
-        return tuple(
-            {
-                k: (
-                    tuple(jnp.asarray(x) for x in v)
-                    if isinstance(v, tuple)
-                    else jnp.asarray(v)
-                )
-                for k, v in t.items()
-            }
-            for t in case.tables
+        if case._device_tables is None:
+            # conversion is idempotent, so a concurrent duplicate would
+            # cost only a wasted copy; the lock keeps assignment clean
+            with self._lock:
+                if case._device_tables is None:
+                    case._device_segdyn = tuple(
+                        jnp.asarray(d) for d in case.seg_dyn
+                    )
+                    case._device_tables = tuple(
+                        {
+                            k: (
+                                tuple(jnp.asarray(x) for x in v)
+                                if isinstance(v, tuple)
+                                else jnp.asarray(v)
+                            )
+                            for k, v in t.items()
+                        }
+                        for t in case.tables
+                    )
+        store = {}
+        for a in case.arrays:
+            flat = np.zeros(case.padded_sizes[a], dtype=np.float64)
+            flat[: case.flat_sizes[a]] = dense.data[a].ravel()
+            store[a] = jnp.asarray(flat)
+        coverage = {}
+        for a in case.sparse:
+            cov = np.zeros(case.padded_sizes[a], dtype=bool)
+            cov[: case.flat_sizes[a]] = dense.mask[a].ravel()
+            coverage[a] = jnp.asarray(cov)
+        return (
+            case.n_levels,
+            case._device_segdyn,
+            case._device_tables,
+            store,
+            coverage,
+            jnp.zeros((2,), bool),
+            jnp.int64(0),
         )
 
     def execute(self, case: PreparedCase, dense: _DenseStore) -> WavefrontStats:
         """Run the artifact on ``dense`` (mutated in place with the result)."""
-
-        import jax.numpy as jnp
-        from jax.experimental import enable_x64
 
         # bucket accounting before dispatch: a fresh trace identity is the
         # only thing that may legitimately re-enter the tracer
@@ -1239,41 +1329,13 @@ class CompiledProgram:
             "xla.bucket_misses" if new_bucket else "xla.bucket_hits"
         ).inc()
 
-        with enable_x64():
+        with x64():
             with _trace.span("xla.to_device"):
-                if case._device_tables is None:
-                    # conversion is idempotent, so a concurrent duplicate
-                    # would cost only a wasted copy; the lock keeps
-                    # assignment clean
-                    with self._lock:
-                        if case._device_tables is None:
-                            case._device_segdyn = tuple(
-                                jnp.asarray(d) for d in case.seg_dyn
-                            )
-                            case._device_tables = self._to_device(case)
-                store = {}
-                for a in case.arrays:
-                    flat = np.zeros(case.padded_sizes[a], dtype=np.float64)
-                    flat[: case.flat_sizes[a]] = dense.data[a].ravel()
-                    store[a] = jnp.asarray(flat)
-                coverage = {}
-                for a in case.sparse:
-                    cov = np.zeros(case.padded_sizes[a], dtype=bool)
-                    cov[: case.flat_sizes[a]] = dense.mask[a].ravel()
-                    coverage[a] = jnp.asarray(cov)
+                args = self.device_args(case, dense)
             # host-side band timing: one level loop per jit call, so the
             # finest host-visible unit is the whole fused level sweep
             with _trace.span("xla.execute", levels=case.n_levels):
-                out_store, out_cov, bad = self._jit(
-                    case.static,
-                    case.n_levels,
-                    case._device_segdyn,
-                    case._device_tables,
-                    store,
-                    coverage,
-                    jnp.zeros((2,), bool),
-                    jnp.int64(0),
-                )
+                out_store, out_cov, bad = self._jit(case.static, *args)
                 # block inside the span: the jit call returns futures, and
                 # an unblocked exit would time dispatch, not execution
                 bad = np.asarray(bad)

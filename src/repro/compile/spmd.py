@@ -288,7 +288,7 @@ class SpmdCompiledProgram(CompiledProgram):
                 static, n_levels, seg_dyn, tables, store, coverage, bad,
                 opaque_zero,
             )
-        from jax.experimental.shard_map import shard_map
+        import jax
         from jax.sharding import PartitionSpec as P
 
         from repro.launch.sharding import _pick
@@ -312,14 +312,14 @@ class SpmdCompiledProgram(CompiledProgram):
 
         # every input and output is replicated (P()); the only sharded
         # values live transiently between the per-device lane slice and the
-        # all_gather inside _lane_values.  check_rep=False because jax
+        # all_gather inside _lane_values.  check_vma=False because jax
         # cannot prove the replication invariant through the gathers.
-        return shard_map(
+        return jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(P(), P(), P(), P(), P(), P(), P()),
             out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )(n_levels, seg_dyn, tables, store, coverage, bad, opaque_zero)
 
     def execute(self, case, dense):

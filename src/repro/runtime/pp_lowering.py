@@ -100,24 +100,13 @@ def build_pipeline_step(
 
     in_specs = (P(axis, None, None), P(None, None, None))
     out_specs = P(None, None, None)
-    if hasattr(jax, "shard_map"):
-        step = jax.shard_map(
-            pipelined,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            check_vma=False,
-        )
-    else:  # pre-0.6 jax: experimental API, replication check spelled check_rep
-        from jax.experimental.shard_map import shard_map
-
-        step = shard_map(
-            pipelined,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            check_rep=False,
-        )
+    step = jax.shard_map(
+        pipelined,
+        mesh=mesh,
+        in_specs=in_specs,
+        out_specs=out_specs,
+        check_vma=False,
+    )
     return step, plan
 
 

@@ -193,6 +193,9 @@ class PlanService:
             max_workers=self.options.workers,
             thread_name_prefix="plan-serve",
         )
+        from repro.compile.lowering import use_persistent_compile_cache
+
+        use_persistent_compile_cache()
         if self.options.warm_profile:
             # load-or-measure the host cost profile before the first
             # request, so every plan this service builds prices strategy
