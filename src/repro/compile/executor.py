@@ -42,8 +42,11 @@ def execute_compiled(
     """
 
     prog = sync.program
-    init = {a: dict(c) for a, c in (store or prog.initial_store()).items()}
-    dense = _DenseStore(init)
+    with _trace.span("store.to_dense"):
+        init = {
+            a: dict(c) for a, c in (store or prog.initial_store()).items()
+        }
+        dense = _DenseStore(init)
     case, table_hit = compiled.prepare(prog, dense)
     if compiled.cache is not None:
         compiled.cache.note_tables(table_hit)
@@ -85,7 +88,8 @@ def execute_compiled(
                     scc_policy=compiled.scc_policy,
                 )
                 return execute_compiled(fallback, sync, store=init)
-    return dense.to_dicts()
+    with _trace.span("store.to_dicts"):
+        return dense.to_dicts()
 
 
 @dataclasses.dataclass

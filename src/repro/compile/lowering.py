@@ -77,6 +77,7 @@ import hashlib
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.obs import metrics as _metrics
 from repro.obs import trace as _trace
@@ -89,6 +90,11 @@ from repro.core.wavefront import (
     _DenseStore,
     schedule_levels,
 )
+
+
+# every span of the program also opens a profiler annotation, so a profiler
+# capture holds the spans on the device trace's clock
+_trace.set_annotation(TraceAnnotation)
 
 
 class XlaLoweringError(ValueError):
@@ -617,17 +623,19 @@ class CompiledProgram:
         """Level tables for these bounds + this store layout (memoized in a
         bounded LRU; thread-safe for concurrent serving)."""
 
-        key = (
-            program.bounds,
-            self._layout_key(dense),
-            self._content_key(program, dense),
-            *self._case_key_extra(),
-        )
-        with self._lock:
-            case = self._cases.get(key)
-            if case is not None:
-                self._cases.move_to_end(key)
-                return case, True
+        with _trace.span("compile.tables_lookup"):
+            key = (
+                program.bounds,
+                self._layout_key(dense),
+                self._content_key(program, dense),
+                *self._case_key_extra(),
+            )
+            with self._lock:
+                case = self._cases.get(key)
+                if case is not None:
+                    self._cases.move_to_end(key)
+        if case is not None:
+            return case, True
         with _trace.span("compile.tables", bounds=str(program.bounds)):
             built = self._build_case(program, dense)
         with self._lock:

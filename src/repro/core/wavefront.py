@@ -602,10 +602,11 @@ def run_wavefront(
     # path pays ONE branch per level (this loop is the interpreter's hot
     # path and the <5% disabled-overhead budget of the bench gate)
     _tracing = _trace.tracing_enabled()
-    _t_level = 0
+    _t_level = _c_level = 0
     for _level, groups in enumerate(sched.levels):
         if _tracing:
             _t_level = time.perf_counter_ns()
+            _c_level = time.thread_time_ns()
         for g in groups:
             stmt, w_l, reads_l, guard_l = lowered[g.statement]
             warr = w_l[1]
@@ -654,6 +655,7 @@ def run_wavefront(
             _trace.emit(
                 "wavefront.level",
                 _t_level,
+                cpu_ns=time.thread_time_ns() - _c_level,
                 level=_level,
                 groups=len(groups),
                 instances=sum(len(g.iterations) for g in groups),

@@ -20,6 +20,16 @@ thread's own spans keep strict stack discipline (pinned by a test).  The
 buffer is a bounded deque guarded by one lock; exceeding the bound drops the
 *oldest* events, so a long serving run keeps its most recent waves.
 
+Every event also records the thread's CPU time over it (``cpu_us``, from
+``time.thread_time_ns``; ``dur - cpu_us`` is time the thread waited: for the
+interpreter lock, a lock or the device) and, inside :class:`request`, the id
+of the request its thread is serving (``req``).  When a profiler annotation
+factory is installed (:func:`set_annotation`; the xla backend installs
+``jax.profiler.TraceAnnotation``), each span also opens one, so a profiler
+capture holds the program's spans on the device trace's clock.  Events
+recorded after the fact (:func:`emit`) cannot be annotated and are not.
+The ``trace.dropped`` counter counts the events the bounded buffer let go.
+
 Everything here is stdlib-only on purpose: this module sits below
 ``repro.core.policy`` in the dependency stack and must never pull in
 numpy/jax.
@@ -32,7 +42,9 @@ import os
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
+
+from . import metrics as _metrics
 
 MAX_EVENTS = 65536
 
@@ -40,6 +52,10 @@ _events: deque = deque(maxlen=MAX_EVENTS)
 _events_lock = threading.Lock()
 _tls = threading.local()
 _enabled = False
+# the profiler annotation each span also opens: factory(name, **metadata)
+# returning a context manager, or None
+_annotate: Optional[Callable[..., Any]] = None
+_DROPPED = _metrics.counter("trace.dropped")
 
 # perf_counter_ns is monotonic but epoch-less; anchor ts=0 at import so
 # exported traces start near zero instead of at machine uptime
@@ -62,6 +78,17 @@ def tracing_enabled() -> bool:
     return _enabled
 
 
+def set_annotation(
+    factory: Optional[Callable[..., Any]],
+) -> Optional[Callable[..., Any]]:
+    """Install the profiler annotation every span also opens while tracing
+    is on (``None`` removes it); returns the factory it replaces."""
+
+    global _annotate
+    prev, _annotate = _annotate, factory
+    return prev
+
+
 class tracing:
     """``with trace.tracing():`` — enable within a block, restore on exit."""
 
@@ -77,6 +104,24 @@ class tracing:
         _enabled = self._prev
 
 
+class request:
+    """``with trace.request(n):`` — every event this thread records inside
+    carries ``req=n``, the request it belongs to."""
+
+    __slots__ = ("_req", "_prev")
+
+    def __init__(self, req: int) -> None:
+        self._req = req
+
+    def __enter__(self) -> "request":
+        self._prev = getattr(_tls, "req", None)
+        _tls.req = self._req
+        return self
+
+    def __exit__(self, *exc) -> None:
+        _tls.req = self._prev
+
+
 def _stack() -> List[str]:
     s = getattr(_tls, "stack", None)
     if s is None:
@@ -84,22 +129,27 @@ def _stack() -> List[str]:
     return s
 
 
-def emit(
+def _record(
     name: str,
+    cat: str,
     t0_ns: int,
-    t1_ns: Optional[int] = None,
-    cat: str = "repro",
-    **args: Any,
+    t1_ns: int,
+    cpu_ns: int,
+    args: Dict[str, Any],
+    stack: List[str],
 ) -> None:
-    """Record one complete event from raw ``perf_counter_ns`` stamps.
+    """Buffer one complete event; ``stack`` is its thread's open spans, the
+    event itself not among them."""
 
-    The low-level hook for hot loops that hoist the enabled check: caller
-    guarantees tracing was enabled when the stamps were taken.
-    """
-
-    if t1_ns is None:
-        t1_ns = time.perf_counter_ns()
-    stack = _stack()
+    args = dict(
+        args,
+        depth=len(stack) + 1,
+        parent=stack[-1] if stack else None,
+        cpu_us=cpu_ns / 1000.0,
+    )
+    req = getattr(_tls, "req", None)
+    if req is not None:
+        args["req"] = req
     ev = {
         "name": name,
         "cat": cat,
@@ -108,14 +158,38 @@ def emit(
         "dur": (t1_ns - t0_ns) / 1000.0,
         "pid": os.getpid(),
         "tid": threading.get_ident(),
-        "args": dict(args, depth=len(stack), parent=stack[-1] if stack else None),
+        "args": args,
     }
     with _events_lock:
+        full = len(_events) == _events.maxlen
         _events.append(ev)
+    if full:
+        _DROPPED.inc()
+
+
+def emit(
+    name: str,
+    t0_ns: int,
+    t1_ns: Optional[int] = None,
+    cat: str = "repro",
+    cpu_ns: int = 0,
+    **args: Any,
+) -> None:
+    """Record one complete event from raw ``perf_counter_ns`` stamps.
+
+    The low-level hook for hot loops that hoist the enabled check: caller
+    guarantees tracing was enabled when the stamps were taken.  ``cpu_ns``
+    is the thread's CPU time over the event, where the caller took it; 0
+    records a wait.
+    """
+
+    if t1_ns is None:
+        t1_ns = time.perf_counter_ns()
+    _record(name, cat, t0_ns, t1_ns, cpu_ns, args, _stack())
 
 
 class _Span:
-    __slots__ = ("name", "cat", "args", "t0")
+    __slots__ = ("name", "cat", "args", "t0", "c0", "ann")
 
     def __init__(self, name: str, cat: str, args: Dict[str, Any]):
         self.name = name
@@ -124,29 +198,29 @@ class _Span:
 
     def __enter__(self) -> "_Span":
         _stack().append(self.name)
+        factory = _annotate
+        self.ann = None
+        if factory is not None:
+            req = getattr(_tls, "req", None)
+            self.ann = (
+                factory(self.name) if req is None
+                else factory(self.name, req=req)
+            )
+            self.ann.__enter__()
+        # the wall interval holds the CPU interval, so cpu_us <= dur
         self.t0 = time.perf_counter_ns()
+        self.c0 = time.thread_time_ns()
         return self
 
     def __exit__(self, *exc) -> None:
+        c1 = time.thread_time_ns()
         t1 = time.perf_counter_ns()
+        if self.ann is not None:
+            self.ann.__exit__(None, None, None)
         stack = _stack()
         stack.pop()
-        ev = {
-            "name": self.name,
-            "cat": self.cat,
-            "ph": "X",
-            "ts": (self.t0 - _T0_NS) / 1000.0,
-            "dur": (t1 - self.t0) / 1000.0,
-            "pid": os.getpid(),
-            "tid": threading.get_ident(),
-            "args": dict(
-                self.args,
-                depth=len(stack) + 1,
-                parent=stack[-1] if stack else None,
-            ),
-        }
-        with _events_lock:
-            _events.append(ev)
+        _record(self.name, self.cat, self.t0, t1, c1 - self.c0, self.args,
+                stack)
 
 
 class _NullSpan:

@@ -35,9 +35,12 @@ Observability (all in the unified ``repro.obs.metrics`` registry, so
 ``obs.reset_all()`` covers them): ``plan_cache.hits`` / ``plan_cache.misses``
 / ``plan_cache.evictions`` / ``plan_cache.artifact_hits`` counters and the
 ``plan_cache.size`` / ``plan_cache.bytes`` gauges for the per-tenant LRUs,
-the ``serve.queue_depth`` gauge, and per-tenant
-``serve.latency_ms.<tenant>`` histograms beside the global
-``serve.plan_ms`` / ``serve.compile_ms`` ones.
+and per-tenant ``serve.latency_ms.<tenant>`` histograms beside the global
+``serve.plan_ms`` / ``serve.compile_ms`` ones.  With tracing on
+(``repro.obs.trace``), each request's events carry its sequence number
+(``req``), and its worker records ``serve.queue`` (the wait for a worker),
+``serve.admit`` (plan LRU, structure locks, artifact lookup or compile) and
+``store.copy`` (the copy of the caller's store) before ``run``.
 """
 
 from __future__ import annotations
@@ -50,6 +53,7 @@ import time
 from typing import Dict, Mapping, Optional, Tuple
 
 from repro.obs import metrics as _metrics
+from repro.obs import trace as _trace
 from repro.core.ir import LoopProgram
 from repro.core.parallelizer import (
     Executable,
@@ -277,7 +281,7 @@ class PlanService:
         *,
         tenant: Optional[str] = None,
     ) -> Tuple[SyncPlan, bool, Tuple[str, Tuple]]:
-        """``resolve`` plus the ``(tenant, key)`` handle ``_handle`` needs
+        """``resolve`` plus the ``(tenant, key)`` handle ``_admit`` needs
         to find the entry again when attaching a compiled artifact."""
 
         tenant = tenant if tenant is not None else self.options.default_tenant
@@ -384,12 +388,13 @@ class PlanService:
                     f"{self.options.max_queue_depth}"
                 )
             self._submitted += 1
+            req = self._submitted
         future = self._pool.submit(
-            self._handle, program, options, tenant, store, run, deadline
+            self._handle, program, options, tenant, store, run, deadline,
+            req, time.perf_counter_ns(),
         )
         with self._lock:
             self._outstanding.add(future)
-            _metrics.gauge("serve.queue_depth").set(len(self._outstanding))
         future.add_done_callback(self._settle)
         return future
 
@@ -397,7 +402,6 @@ class PlanService:
         with self._lock:
             self._outstanding.discard(future)
             self._completed += 1
-            _metrics.gauge("serve.queue_depth").set(len(self._outstanding))
 
     def _handle(
         self,
@@ -406,17 +410,54 @@ class PlanService:
         tenant: Optional[str],
         store: Optional[Mapping[str, dict]],
         run: bool,
-        deadline: Optional[float] = None,
+        deadline: Optional[float],
+        req: int,
+        submitted_ns: int,
     ) -> ServiceResult:
-        tenant = tenant if tenant is not None else self.options.default_tenant
-        t0 = time.perf_counter()
-        if deadline is not None and t0 > deadline:
-            _metrics.counter("serve.deadline_drops").inc()
-            raise RuntimeError(
-                f"request dropped at dequeue: queued "
-                f"{(t0 - deadline) * 1e3:.1f}ms past its deadline "
-                f"(deadline_ms admission control)"
+        with _trace.request(req):
+            if _trace.tracing_enabled():
+                _trace.emit("serve.queue", submitted_ns)
+            t0 = time.perf_counter()
+            if deadline is not None and t0 > deadline:
+                _metrics.counter("serve.deadline_drops").inc()
+                raise RuntimeError(
+                    f"request dropped at dequeue: queued "
+                    f"{(t0 - deadline) * 1e3:.1f}ms past its deadline "
+                    f"(deadline_ms admission control)"
+                )
+            with _trace.span("serve.admit"):
+                plan_obj, cached, tenant, executable = self._admit(
+                    program, options, tenant
+                )
+            out = None
+            if run or store is not None:
+                with _trace.span("store.copy"):
+                    init = {
+                        a: dict(c)
+                        for a, c in (store or program.initial_store()).items()
+                    }
+                out = executable.run(store=init)
+            latency = (time.perf_counter() - t0) * 1e3
+            _metrics.histogram(f"serve.latency_ms.{tenant}").observe(latency)
+            return ServiceResult(
+                tenant=tenant,
+                plan=plan_obj,
+                executable=executable,
+                store=out,
+                plan_cached=cached,
+                latency_ms=latency,
             )
+
+    def _admit(
+        self,
+        program: LoopProgram,
+        options: Optional[PlanOptions],
+        tenant: Optional[str],
+    ) -> Tuple[SyncPlan, bool, str, Executable]:
+        """The request's plan (through the tenant's LRU) and its compiled
+        artifact, compiled and attached to the entry on a first request;
+        returns ``(plan, plan_cached, tenant, executable)``."""
+
         plan_obj, cached, (tenant, key) = self._resolve_entry(
             program, options, tenant=tenant
         )
@@ -457,23 +498,7 @@ class PlanService:
         _metrics.histogram("serve.compile_ms").observe(
             (time.perf_counter() - tc) * 1e3
         )
-        out = None
-        if run or store is not None:
-            init = {
-                a: dict(c)
-                for a, c in (store or program.initial_store()).items()
-            }
-            out = executable.run(store=init)
-        latency = (time.perf_counter() - t0) * 1e3
-        _metrics.histogram(f"serve.latency_ms.{tenant}").observe(latency)
-        return ServiceResult(
-            tenant=tenant,
-            plan=plan_obj,
-            executable=executable,
-            store=out,
-            plan_cached=cached,
-            latency_ms=latency,
-        )
+        return plan_obj, cached, tenant, executable
 
     def drain(self, timeout: Optional[float] = None) -> dict:
         """Block until every outstanding request settles; returns

@@ -185,6 +185,8 @@ class TestTracer:
             for i in range(trace.MAX_EVENTS + 50):
                 trace.emit("tick", time.perf_counter_ns())
         assert len(trace.events()) == trace.MAX_EVENTS
+        # the events let go are counted, so a long traced window says so
+        assert metrics.counter("trace.dropped").value == 50
 
     def test_traced_overhead_stays_bounded(self):
         """Tracing on vs off around the same plan().compile().run() —
@@ -212,6 +214,226 @@ class TestTracer:
         assert traced <= max(untraced, 1e-4) * 10, (
             f"traced={traced*1e6:.0f}us untraced={untraced*1e6:.0f}us"
         )
+
+
+# ---------------------------------------------------------------------- #
+# Request-scoped spans of the plan service
+# ---------------------------------------------------------------------- #
+
+SERVED_REQUESTS = 8
+# the spans every served request records, each once, on its worker
+REQUEST_SPANS = ("serve.queue", "serve.admit", "store.copy", "run")
+# the spans every xla run records directly under ``run``
+RUN_CHILDREN = (
+    "store.to_dense",
+    "compile.tables_lookup",
+    "xla.to_device",
+    "xla.execute",
+    "xla.to_host",
+    "store.to_dicts",
+)
+
+
+@pytest.fixture(scope="class")
+def served_events():
+    """The trace of SERVED_REQUESTS requests for two structures, cold ones
+    among them, through a two-worker xla service with tracing on."""
+
+    from repro.serve import PlanService, ServiceOptions
+
+    trace.disable()
+    obs.reset_all()
+    programs = (paper_alg6(8), _recurrence_program(4, 8))
+    try:
+        with PlanService(ServiceOptions(backend="xla", workers=2)) as svc:
+            with trace.tracing():
+                futures = [
+                    svc.submit(programs[i % 2], run=True)
+                    for i in range(SERVED_REQUESTS)
+                ]
+                for f in futures:
+                    f.result(timeout=600)
+        events = trace.events()
+        snapshot = metrics.snapshot()
+    finally:
+        trace.disable()
+        obs.reset_all()
+    return events, snapshot
+
+
+def _by_request(events):
+    out = {}
+    for e in events:
+        out.setdefault(e["args"].get("req"), []).append(e)
+    return out
+
+
+class _Recorder:
+    """A fake profiler annotation factory that logs enters and exits."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name, **metadata):
+        log = self.log
+
+        class _Annotation:
+            def __enter__(self):
+                log.append(("enter", name, metadata))
+
+            def __exit__(self, *exc):
+                log.append(("exit", name, metadata))
+
+        return _Annotation()
+
+
+class TestRequestSpans:
+    def test_each_request_has_its_serve_spans_and_ends_with_run(
+        self, served_events
+    ):
+        events, _ = served_events
+        by_req = _by_request(events)
+        assert sorted(by_req) == list(range(1, SERVED_REQUESTS + 1))
+        for req, evs in by_req.items():
+            names = [e["name"] for e in evs if e["args"]["depth"] == 1]
+            for name in REQUEST_SPANS:
+                assert names.count(name) == 1, (req, names)
+            last = max(evs, key=lambda e: e["ts"] + e["dur"])
+            assert last["name"] == "run" and last["args"]["depth"] == 1
+            # the serve spans close before run opens: none encloses it
+            run = last
+            for e in evs:
+                if e["name"] in REQUEST_SPANS[:-1]:
+                    assert e["ts"] + e["dur"] <= run["ts"], (req, e["name"])
+
+    def test_store_and_device_spans_sit_under_run(self, served_events):
+        events, _ = served_events
+        for req, evs in _by_request(events).items():
+            children = [e for e in evs if e["args"]["parent"] == "run"]
+            names = [e["name"] for e in children]
+            for name in RUN_CHILDREN:
+                assert names.count(name) == 1, (req, names)
+            assert all(e["args"]["depth"] == 2 for e in children)
+        # a cold request plans and compiles inside serve.admit
+        parents = {e["name"]: e["args"]["parent"] for e in events}
+        assert parents["plan"] == "serve.admit"
+        assert parents["compile"] == "serve.admit"
+        assert parents["compile.tables"] == "run"
+
+    def test_every_event_of_a_request_carries_its_req(self, served_events):
+        events, _ = served_events
+        assert all("req" in e["args"] for e in events)
+        for req, evs in _by_request(events).items():
+            # one worker thread serves the whole request
+            assert len({e["tid"] for e in evs}) == 1, req
+
+    def test_cpu_time_lies_within_wall_time(self, served_events):
+        events, _ = served_events
+        for e in events:
+            cpu = e["args"]["cpu_us"]
+            assert 0 <= cpu <= e["dur"] + 1, e
+        # the queue is a wait: its thread did no work for it
+        assert all(
+            e["args"]["cpu_us"] == 0
+            for e in events if e["name"] == "serve.queue"
+        )
+
+    def test_queue_depth_gauge_is_gone(self, served_events):
+        _, snapshot = served_events
+        assert "serve.queue_depth" not in snapshot
+        assert snapshot["serve.compile_ms"]["count"] == SERVED_REQUESTS
+
+    def test_emit_depth_equals_span_depth_at_the_same_place(self):
+        with trace.tracing():
+            trace.emit("top.emit", time.perf_counter_ns())
+            with trace.span("top.span"):
+                trace.emit("inner.emit", time.perf_counter_ns())
+                with trace.span("inner.span"):
+                    pass
+        args = {e["name"]: e["args"] for e in trace.events()}
+        assert args["top.emit"]["depth"] == args["top.span"]["depth"] == 1
+        assert args["inner.emit"]["depth"] == args["inner.span"]["depth"] == 2
+        assert args["inner.emit"]["parent"] == "top.span"
+        # outside a request an event carries no req
+        assert all("req" not in a for a in args.values())
+
+    def test_request_scope_sets_and_restores_req(self):
+        with trace.tracing():
+            with trace.request(7):
+                with trace.span("a"):
+                    with trace.request(8):
+                        trace.emit("b", time.perf_counter_ns())
+                trace.emit("c", time.perf_counter_ns())
+            trace.emit("d", time.perf_counter_ns())
+        reqs = {e["name"]: e["args"].get("req") for e in trace.events()}
+        assert reqs == {"a": 7, "b": 8, "c": 7, "d": None}
+
+    def test_annotations_follow_the_span_stack(self):
+        recorder = _Recorder()
+        prev = trace.set_annotation(recorder)
+        try:
+            with trace.span("off"):
+                pass
+            assert recorder.log == []  # nothing is called with tracing off
+            with trace.tracing():
+                with trace.request(3):
+                    with trace.span("outer"):
+                        trace.emit("retro", time.perf_counter_ns())
+                        with trace.span("inner"):
+                            pass
+                with trace.span("free"):
+                    pass
+        finally:
+            trace.set_annotation(prev)
+        assert recorder.log == [
+            ("enter", "outer", {"req": 3}),
+            ("enter", "inner", {"req": 3}),
+            ("exit", "inner", {"req": 3}),
+            ("exit", "outer", {"req": 3}),
+            ("enter", "free", {}),
+            ("exit", "free", {}),
+        ]
+
+    def test_xla_backend_installs_the_profiler_annotation(self):
+        import jax
+
+        from repro.compile import lowering
+
+        assert lowering.TraceAnnotation is jax.profiler.TraceAnnotation
+        assert trace.set_annotation(None) is jax.profiler.TraceAnnotation
+        trace.set_annotation(jax.profiler.TraceAnnotation)
+
+    def test_level_loop_module_keeps_the_name_the_bench_finds(self):
+        """The benchmark reads the level loop's device time from the
+        profiler's module events whose name holds ``LEVEL_LOOP_MODULE``
+        (bench/harness.py); a rename of the jitted function would leave
+        that metric with nothing to read."""
+
+        import ast
+        from pathlib import Path
+
+        from repro.compile.lowering import x64
+        from repro.core.wavefront import _DenseStore
+
+        harness = Path(__file__).resolve().parents[1] / "bench" / "harness.py"
+        part = next(
+            node.value.value
+            for node in ast.parse(harness.read_text()).body
+            if isinstance(node, ast.Assign)
+            and [t.id for t in node.targets] == ["LEVEL_LOOP_MODULE"]
+        )
+        prog = paper_alg6(8)
+        compiled = plan(prog, method="isd").compile("xla").compiled
+        dense = _DenseStore(
+            {a: dict(c) for a, c in prog.initial_store().items()}
+        )
+        case, _ = compiled.prepare(prog, dense)
+        with x64():
+            lowered = compiled._jit.lower(
+                case.static, *compiled.device_args(case, dense)
+            )
+        module = lowered.as_text().split()[1]  # "module @jit__exec ..."
+        assert part in module, (part, module)
 
 
 # ---------------------------------------------------------------------- #
