@@ -39,6 +39,11 @@ def execute_compiled(
     backend: no structural-cache lookup (the artifact is in hand), only the
     per-(bounds, layout) table cache and jax's jit cache underneath — which
     is what makes ``plan once, compile once, run many`` the warm path.
+
+    The returned store holds every array of the input store.  Only the
+    program's write set is converted back from the device; every read-only
+    array is passed through as a dict copy of the caller's cells, not
+    converted (see :func:`_reply`).
     """
 
     prog = sync.program
@@ -88,8 +93,25 @@ def execute_compiled(
                     scc_policy=compiled.scc_policy,
                 )
                 return execute_compiled(fallback, sync, store=init)
-    with _trace.span("store.to_dicts"):
-        return dense.to_dicts()
+    return _reply(case, dense, init)
+
+
+def _reply(case, dense: _DenseStore, init: dict) -> dict:
+    """The store a run returns: the arrays ``case`` writes converted back
+    from ``dense``, every other array passed through as ``init``'s copy of
+    the caller's cells (a run cannot change it)."""
+
+    written = case.written
+    cells = sum(
+        int(dense.mask[a].sum()) if a in dense.mask else dense.data[a].size
+        for a in written
+    )
+    _metrics.counter("store.passthrough_cells").inc(
+        sum(len(c) for a, c in init.items() if a not in written)
+    )
+    with _trace.span("store.to_dicts", arrays=",".join(written), cells=cells):
+        out = dense.to_dicts(written)
+    return {a: out.get(a, c) for a, c in init.items()}
 
 
 @dataclasses.dataclass
@@ -170,7 +192,7 @@ def run_xla(
         case, table_hit = compiled.prepare(prog, dense)
         cache.note_tables(table_hit)
         stats = compiled.execute(case, dense)
-        result = dense.to_dicts()
+        result = _reply(case, dense, init)
 
     matches = True
     if compare:

@@ -370,6 +370,9 @@ class PreparedCase:
     flat_sizes: Dict[str, int]                  # live cells per array
     padded_sizes: Dict[str, int]                # flat buffer length (≥ live+1)
     sparse: Tuple[str, ...]                     # arrays carrying coverage
+    # arrays some statement writes: the only ones a run can change, so the
+    # only ones copied back (never part of the trace identity)
+    written: Tuple[str, ...]
     schedule: WavefrontSchedule
     # per-segment dynamic scalars (see _CaseStatic.segments):
     #   wave → [lo, hi, cursors0…] ; rec → [n_chunks, row0…]
@@ -697,6 +700,8 @@ class CompiledProgram:
         flat_sizes = {a: int(np.prod(shapes[a])) for a in arrays}
         padded_sizes = {a: _next_pow2(flat_sizes[a] + 1) for a in arrays}
         sparse = tuple(a for a in arrays if a in dense.mask)
+        targets = {s.write.array for s in program.statements}
+        written = tuple(a for a in arrays if a in targets)
 
         per_stmt: Dict[str, List[Tuple[int, np.ndarray]]] = {}
         for lvl, groups in enumerate(sched.levels):
@@ -897,6 +902,7 @@ class CompiledProgram:
             flat_sizes=flat_sizes,
             padded_sizes=padded_sizes,
             sparse=sparse,
+            written=written,
             schedule=sched,
             seg_dyn=seg_dyn,
             bucket=bucket,
@@ -1325,7 +1331,12 @@ class CompiledProgram:
         )
 
     def execute(self, case: PreparedCase, dense: _DenseStore) -> WavefrontStats:
-        """Run the artifact on ``dense`` (mutated in place with the result)."""
+        """Run the artifact on ``dense`` (mutated in place with the result).
+
+        Only the written arrays (``case.written``), and the coverage of the
+        sparse ones among them, are copied back: every other array of
+        ``dense`` keeps the host values it came in with.
+        """
 
         # bucket accounting before dispatch: a fresh trace identity is the
         # only thing that may legitimately re-enter the tracer
@@ -1354,13 +1365,14 @@ class CompiledProgram:
                     a: np.asarray(out_store[a])[: case.flat_sizes[a]].reshape(
                         case.shapes[a]
                     )
-                    for a in case.arrays
+                    for a in case.written
                 }
                 cov_np = {
                     a: np.asarray(out_cov[a])[: case.flat_sizes[a]].reshape(
                         case.shapes[a]
                     )
                     for a in case.sparse
+                    if a in out_np
                 }
         if bad[0]:
             raise KeyError(_OOB_MSG)

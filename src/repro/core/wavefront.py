@@ -55,7 +55,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import time
-from typing import Dict, List, Mapping, Optional, Sequence, Tuple
+from typing import Dict, Iterable, List, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -452,9 +452,18 @@ class _DenseStore:
         if covered is not None:
             covered[idx] = True
 
-    def to_dicts(self) -> dict:
+    def to_dicts(self, arrays: Optional[Iterable[str]] = None) -> dict:
+        """Convert ``arrays`` (default: every array) back to dicts.
+
+        The compiled backends convert only the arrays their program writes
+        and pass every read-only array through as the caller's own cells,
+        not converted (:func:`repro.compile.executor.execute_compiled`);
+        :func:`run_wavefront` converts every array.
+        """
+
         out: dict = {}
-        for arr, dense in self.data.items():
+        for arr in self.data if arrays is None else arrays:
+            dense = self.data[arr]
             lo = self.origin[arr]
             covered = self.mask.get(arr)
             if covered is None:
