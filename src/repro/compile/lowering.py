@@ -378,6 +378,10 @@ class PreparedCase:
     #   wave → [lo, hi, cursors0…] ; rec → [n_chunks, row0…]
     seg_dyn: Tuple[np.ndarray, ...] = ()
     bucket: Tuple = ()                          # trace-identity key (host view)
+    # lanes a run computes with the mask set, and lanes it computes in all
+    # (each statement's groups × its padded width): the xla.lanes_* counters
+    lanes_live: int = 0
+    lanes_padded: int = 0
     _device_tables: Optional[Tuple] = None      # jnp copies, converted once
     _device_segdyn: Optional[Tuple] = None
 
@@ -578,21 +582,34 @@ class CompiledProgram:
             )
         )
 
-    @staticmethod
-    def _content_key(program: LoopProgram, dense: _DenseStore) -> Optional[str]:
-        """Index-array content digest for indirect programs.
+    def _content_arrays(self, program: LoopProgram) -> Tuple[str, ...]:
+        """The arrays whose contents the level tables depend on: the index
+        arrays, and under ``deps="inspect"`` the read-only guard arrays the
+        inspector resolves."""
+
+        if self.deps_mode == "inspect":
+            from repro.core.inspector import content_arrays
+
+            return content_arrays(program)
+        return program.index_arrays()
+
+    def _content_key(
+        self, program: LoopProgram, dense: _DenseStore
+    ) -> Optional[str]:
+        """Content digest of :meth:`_content_arrays` for indirect programs.
 
         The level tables of an indirect access are computed from the index
         array's *values* (and, under ``deps="inspect"``, so is the schedule
-        itself), so the per-bounds case key must cover them — this is where
-        store-dependent state lives, never in the bounds-free structural key.
-        Affine programs return None and pay nothing.
+        itself, with the live set its guards leave), so the per-bounds case
+        key must cover them — this is where store-dependent state lives,
+        never in the bounds-free structural key.  Affine programs return
+        None and pay nothing.
         """
 
         if not program.has_indirect():
             return None
         h = hashlib.sha1()
-        for arr in sorted(program.index_arrays()):
+        for arr in sorted(self._content_arrays(program)):
             h.update(arr.encode())
             h.update(repr(dense.origin[arr]).encode())
             h.update(dense.data[arr].tobytes())
@@ -601,24 +618,10 @@ class CompiledProgram:
                 h.update(covered.tobytes())
         return h.hexdigest()
 
-    @staticmethod
-    def _index_store(program: LoopProgram, dense: _DenseStore) -> dict:
-        """Dict-form view of just the index arrays (inspector input)."""
+    def _index_store(self, program: LoopProgram, dense: _DenseStore) -> dict:
+        """Dict-form view of just the content arrays (inspector input)."""
 
-        out: dict = {}
-        for arr in program.index_arrays():
-            d = dense.data[arr]
-            lo = dense.origin[arr]
-            covered = dense.mask.get(arr)
-            cells = {}
-            for idx in np.ndindex(d.shape):
-                if covered is not None and not covered[idx]:
-                    continue
-                cells[tuple(int(x + l) for x, l in zip(idx, lo))] = float(
-                    d[idx]
-                )
-            out[arr] = cells
-        return out
+        return dense.to_dicts(self._content_arrays(program))
 
     def prepare(
         self, program: LoopProgram, dense: _DenseStore
@@ -667,7 +670,7 @@ class CompiledProgram:
         level_cost = self._level_cost_hook()
 
         retained = list(self.retained)
-        instance_edges = None
+        instance_edges, inactive = None, frozenset()
         if self.deps_mode is not None and program.has_indirect():
             from repro.core.inspector import (
                 affine_retained,
@@ -680,9 +683,11 @@ class CompiledProgram:
             # validates post-hoc and rolls back to the deps=None artifact)
             retained = list(affine_retained(retained))
             if self.deps_mode == "inspect":
-                instance_edges = inspect_dependences(
+                inspection = inspect_dependences(
                     program, self._index_store(program, dense)
-                ).edges
+                )
+                instance_edges = inspection.edges
+                inactive = inspection.inactive
         sched = schedule_levels(
             program,
             retained,
@@ -692,6 +697,7 @@ class CompiledProgram:
             scc_policy=self.scc_policy,
             level_cost=level_cost,
             instance_edges=instance_edges,
+            inactive=inactive,
         )
         n_levels = sched.depth
         arrays = tuple(sorted(dense.data))
@@ -906,11 +912,18 @@ class CompiledProgram:
             schedule=sched,
             seg_dyn=seg_dyn,
             bucket=bucket,
+            lanes_live=sched.instances,
+            lanes_padded=sum(len(w) * wp for w, wp in zip(row_widths, wps)),
         )
 
     # Minimum run of uniform levels worth collapsing into a nested loop —
     # below this the generic dispatcher's per-level cost doesn't matter.
     REC_BAND_MIN = 4
+    # Most bands one case lowers: each compiles a nested loop of its own
+    # (2·L+1 of them with the width ladder), so compile time grows with
+    # their count; past the longest MAX_BANDS, a band's levels go to the
+    # generic dispatcher.
+    MAX_BANDS = 4
 
     def _band_rungs(self, wpb: int) -> int:
         """Width-ladder depth for a recurrence band of padded width
@@ -1055,7 +1068,7 @@ class CompiledProgram:
                 np.asarray([lo, hi, *cursors_at(lo)], dtype=np.int32)
             )
 
-        wave_start = 0
+        bands = []
         L = 0
         while L < n_levels:
             base = level_active[L]
@@ -1068,16 +1081,18 @@ class CompiledProgram:
             ):
                 run += 1
             if base and run >= CompiledProgram.REC_BAND_MIN:
-                if wave_start < L:
-                    wave(wave_start, L)
-                skeleton.append(("rec", tuple(k for k, _ in base)))
-                seg_dyn.append(
-                    np.asarray(
-                        [run, *(r0 for _, r0 in base)], dtype=np.int32
-                    )
-                )
-                wave_start = L + run
+                bands.append((L, run, base))
             L += run
+        longest = sorted(bands, key=lambda b: -b[1])
+        wave_start = 0
+        for L, run, base in sorted(longest[: CompiledProgram.MAX_BANDS]):
+            if wave_start < L:
+                wave(wave_start, L)
+            skeleton.append(("rec", tuple(k for k, _ in base)))
+            seg_dyn.append(
+                np.asarray([run, *(r0 for _, r0 in base)], dtype=np.int32)
+            )
+            wave_start = L + run
         if wave_start < n_levels:
             wave(wave_start, n_levels)
         return tuple(skeleton), tuple(seg_dyn)
@@ -1347,6 +1362,9 @@ class CompiledProgram:
         _metrics.counter(
             "xla.bucket_misses" if new_bucket else "xla.bucket_hits"
         ).inc()
+        _metrics.counter("xla.levels").inc(case.n_levels)
+        _metrics.counter("xla.lanes_live").inc(case.lanes_live)
+        _metrics.counter("xla.lanes_padded").inc(case.lanes_padded)
 
         with x64():
             with _trace.span("xla.to_device"):

@@ -27,9 +27,14 @@ construction, so results live in a bounded per-bounds memo keyed by
 (program fingerprint, bounds, index-array content digest) — beside the
 level-table cache, never inside the bounds-free structural key.
 
-Guards are treated as always-executing during inspection (their outcome can
-depend on loop-computed values): a superset of the real access set, hence a
-superset of the real edges — over-serialization, never under.
+Guards over an array that no statement writes are known at loop entry, just
+as subscripts are: an instance whose guard there is not positive never runs,
+so it leaves the instance graph (:attr:`InspectionResult.inactive`) and the
+schedule lays out only the live instances — the paper's control dependence
+(§2.1), resolved at run time.  A guard over a written array can depend on
+loop-computed values, so its statement is treated as always executing: a
+superset of the real access set, hence a superset of the real edges —
+over-serialization, never under.
 """
 
 from __future__ import annotations
@@ -37,8 +42,9 @@ from __future__ import annotations
 import collections
 import dataclasses
 import hashlib
+import math
 import threading
-from typing import Dict, List, Mapping, Optional, Sequence, Tuple
+from typing import Dict, FrozenSet, List, Mapping, Optional, Sequence, Tuple
 
 from repro.obs import metrics as _metrics
 from repro.obs import trace as _trace
@@ -66,6 +72,9 @@ class InspectionResult:
     # (earlier instance, later instance) in sequential order; same-iteration
     # conflicts are omitted (intra-iteration program order enforces them)
     edges: Tuple[InstanceEdge, ...]
+    # instances whose guard over a read-only array is not positive: they
+    # never run, take part in no edge and are laid out in no level
+    inactive: FrozenSet[Instance] = frozenset()
 
     @property
     def conflict_free(self) -> bool:
@@ -76,6 +85,7 @@ class InspectionResult:
             "arrays": list(self.arrays),
             "edges": len(self.edges),
             "conflict_free": self.conflict_free,
+            "inactive": len(self.inactive),
         }
 
 
@@ -106,54 +116,109 @@ def _inspected_arrays(prog: LoopProgram) -> Tuple[str, ...]:
     return tuple(seen)
 
 
+def resolved_guard_arrays(prog: LoopProgram) -> Tuple[str, ...]:
+    """Guard arrays no statement writes: fixed for the whole loop, so the
+    inspector evaluates the guards over them as it evaluates subscripts."""
+
+    written = {s.write.array for s in prog.statements}
+    seen: List[str] = []
+    for s in prog.statements:
+        g = s.guard
+        if g is not None and g.array not in written and g.array not in seen:
+            seen.append(g.array)
+    return tuple(seen)
+
+
+def content_arrays(prog: LoopProgram) -> Tuple[str, ...]:
+    """The arrays whose contents the instance graph depends on: the index
+    arrays and the read-only guard arrays."""
+
+    out = list(prog.index_arrays())
+    out += [a for a in resolved_guard_arrays(prog) if a not in out]
+    return tuple(out)
+
+
 def _compute_edges(
     prog: LoopProgram, store: Mapping[str, dict]
-) -> Tuple[InstanceEdge, ...]:
+) -> Tuple[Tuple[InstanceEdge, ...], FrozenSet[Instance], Dict[str, int]]:
     """One sequential sweep with per-cell last-writer/reader tracking.
 
     Near-linear in the access count: every read sits in at most one
     "readers since last write" list and is flushed by at most one later
     write, so |edges| = O(|accesses|) — the O(n²) pairwise comparison exists
     only as the test-side cross-check (tests/test_inspector.py).
+
+    Returns the edges, the inactive instances (a read-only guard not
+    positive; a guard cell the store lacks counts as live) and the sweep's
+    counts: edges by kind, and the depth and widest (statement, level)
+    group of the live graph under program order — the levels the doall
+    machine lays out before any affine dependence joins the graph.
     """
 
     targets = set(_inspected_arrays(prog))
+    resolved = set(resolved_guard_arrays(prog))
     last_write: Dict[Tuple[str, Tuple[int, ...]], Instance] = {}
     readers: Dict[Tuple[str, Tuple[int, ...]], List[Instance]] = {}
     edges: List[InstanceEdge] = []
     seen: set = set()
+    inactive: List[Instance] = []
+    kinds = {"flow": 0, "anti": 0, "output": 0}
+    level: Dict[Instance, int] = {}
+    groups: "collections.Counter[Tuple[str, int]]" = collections.Counter()
 
-    def emit(u: Instance, v: Instance) -> None:
+    def emit(u: Instance, v: Instance, kind: str) -> None:
         if u[1] == v[1]:
             return  # same iteration: intra-iteration program order covers it
         if (u, v) not in seen:
             seen.add((u, v))
             edges.append((u, v))
+            kinds[kind] += 1
 
     for it in prog.iterations():
+        after = 0  # program order: the least level the next statement takes
         for s in prog.statements:
             inst = (s.name, it)
+            g = s.guard
+            if g is not None and g.array in resolved:
+                val = store[g.array].get(ref_cell(g, it, store))
+                if val is not None and not val > 0:
+                    inactive.append(inst)
+                    continue
+            preds: List[Instance] = []
             reads = list(s.reads)
-            if s.guard is not None:
-                reads.append(s.guard)  # conservatively always evaluated
+            if g is not None:
+                reads.append(g)  # conservatively always evaluated
             for r in reads:
                 if r.array not in targets:
                     continue
                 cell = (r.array, ref_cell(r, it, store))
                 lw = last_write.get(cell)
                 if lw is not None:
-                    emit(lw, inst)  # flow
+                    emit(lw, inst, "flow")
+                    preds.append(lw)
                 readers.setdefault(cell, []).append(inst)
             w = s.write
             if w.array in targets:
                 cell = (w.array, ref_cell(w, it, store))
                 for rd in readers.pop(cell, ()):
-                    emit(rd, inst)  # anti
+                    if rd != inst:
+                        emit(rd, inst, "anti")
+                        preds.append(rd)
                 lw = last_write.get(cell)
                 if lw is not None:
-                    emit(lw, inst)  # output
+                    emit(lw, inst, "output")
+                    preds.append(lw)
                 last_write[cell] = inst
-    return tuple(edges)
+            lvl = max([after] + [level[p] + 1 for p in preds])
+            level[inst] = lvl
+            groups[(s.name, lvl)] += 1
+            after = lvl + 1
+    counts = dict(
+        kinds,
+        levels=max(level.values(), default=-1) + 1,
+        widest=max(groups.values(), default=0),
+    )
+    return tuple(edges), frozenset(inactive), counts
 
 
 # ---------------------------------------------------------------------- #
@@ -171,14 +236,17 @@ _INSPECTOR_LOCK = threading.Lock()
 # re-inspection count the serving summary reports)
 _INSPECTOR_HITS = _metrics.counter("inspector_cache.hits")
 _INSPECTOR_MISSES = _metrics.counter("inspector_cache.misses")
+# instances the inspections left out by a resolved guard
+_GUARDED_OUT = _metrics.counter("inspector.guarded_out")
 
 
 def index_content_digest(prog: LoopProgram, store: Mapping[str, dict]) -> str:
-    """Content digest of the index arrays — the part of the store the
-    instance graph actually depends on (subscripts are loop-invariant)."""
+    """Content digest of the index and read-only guard arrays — the part of
+    the store the instance graph actually depends on (both are
+    loop-invariant)."""
 
     h = hashlib.sha1()
-    for arr in prog.index_arrays():
+    for arr in content_arrays(prog):
         h.update(arr.encode())
         for cell, val in sorted(store[arr].items()):
             # normalize the value type: a wave passing {"bin": [0, 1]} and
@@ -238,10 +306,21 @@ def inspect_dependences(
         _INSPECTOR_HITS.inc()
         return cached
     _INSPECTOR_MISSES.inc()
-    with _trace.span("inspect", statements=len(prog.statements)):
+    with _trace.span("inspect", statements=len(prog.statements)) as sp:
+        edges, inactive, counts = _compute_edges(prog, mem)
         result = InspectionResult(
-            program=prog, arrays=arrays, edges=_compute_edges(prog, mem)
+            program=prog, arrays=arrays, edges=edges, inactive=inactive
         )
+        sp.note(
+            slots=math.prod(hi - lo for lo, hi in prog.bounds),
+            edges_flow=counts["flow"],
+            edges_anti=counts["anti"],
+            edges_output=counts["output"],
+            guarded_out=len(inactive),
+            levels=counts["levels"],
+            widest=counts["widest"],
+        )
+    _GUARDED_OUT.inc(len(inactive))
     with _INSPECTOR_LOCK:
         _INSPECTOR_MEMO[key] = result
         while len(_INSPECTOR_MEMO) > _INSPECTOR_MEMO_MAX:

@@ -1000,6 +1000,7 @@ def _wavefront_run(sync, artifacts, *, store=None, stalls=None):
         chunk_limit=opts.get("chunk_limit"),
         scc_policy=opts.get("scc_policy"),
         instance_edges=inspection.edges,
+        inactive=inspection.inactive,
     )
     return run_wavefront(sync, schedule=exact, store=init, compare=False).store
 

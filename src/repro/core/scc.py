@@ -57,7 +57,7 @@ heavy import.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Sequence, Set, Tuple
+from typing import Collection, Dict, List, Optional, Sequence, Set, Tuple
 
 from repro.obs import metrics as _metrics
 from repro.core.dependence import Dependence
@@ -536,6 +536,7 @@ def hybrid_levels(
     scc_policy: SccPolicyLike = None,
     level_cost: Optional[LevelCostFn] = None,
     instance_edges: Optional[Sequence[Tuple[Instance, Instance]]] = None,
+    inactive: Collection[Instance] = frozenset(),
 ) -> Tuple[List[Dict[str, List[Tuple[int, ...]]]], SccPartition]:
     """Longest-path layering over mixed instance/chunk scheduling units.
 
@@ -573,7 +574,10 @@ def hybrid_levels(
         :func:`analyze_sccs`), so both-direction instance conflicts merge
         into one SCC and cannot close a cross-unit cycle; an instance edge
         that would land *inside* one chunk span shrinks that SCC's chunk to
-        1 (always sound — smaller chunks only serialize more).
+        1 (always sound — smaller chunks only serialize more);
+      * ``inactive`` instances (resolved out by the inspector) are emitted
+        in no level; as instance units they add no level to a path through
+        them, so the orders between the live units around them still hold.
     """
 
     part = analyze_sccs(
@@ -715,8 +719,9 @@ def hybrid_levels(
         nxt: List[Unit] = []
         for u in frontier:
             done += 1
+            after = level[u] + (u[0] != "i" or (u[1], u[2]) not in inactive)
             for v in adj.get(u, ()):
-                level[v] = max(level.get(v, 0), level[u] + 1)
+                level[v] = max(level.get(v, 0), after)
                 indeg[v] -= 1
                 if indeg[v] == 0:
                     nxt.append(v)
@@ -736,7 +741,7 @@ def hybrid_levels(
     for it in pts:
         for s in names:
             u = unit(s, it)
-            if u[0] == "i":
+            if u[0] == "i" and (s, it) not in inactive:
                 levels[level[u]].setdefault(s, []).append(it)
     # chunk units expand to one batch per member statement (lexical order)
     for info in chunk_info.values():
@@ -745,5 +750,8 @@ def hybrid_levels(
             lvl = level[("c", info.id, t)]
             span = pts[t * info.chunk : (t + 1) * info.chunk]
             for s in info.statements:
-                levels[lvl][s] = list(span)
-    return levels, part
+                live = [it for it in span if (s, it) not in inactive]
+                if live:
+                    levels[lvl][s] = live
+    # a level left with inactive instances alone holds no work
+    return [lv for lv in levels if lv], part

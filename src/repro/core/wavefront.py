@@ -55,7 +55,16 @@ from __future__ import annotations
 import dataclasses
 import functools
 import time
-from typing import Dict, Iterable, List, Mapping, Optional, Sequence, Tuple
+from typing import (
+    Collection,
+    Dict,
+    Iterable,
+    List,
+    Mapping,
+    Optional,
+    Sequence,
+    Tuple,
+)
 
 import numpy as np
 
@@ -237,6 +246,7 @@ def schedule_levels(
     scc_policy: "SccPolicyLike" = None,
     level_cost: Optional["LevelCostFn"] = None,
     instance_edges: Optional[Sequence[Tuple[Instance, Instance]]] = None,
+    inactive: Collection[Instance] = frozenset(),
 ) -> WavefrontSchedule:
     """Layer a bare :class:`LoopProgram` given its retained dependences.
 
@@ -249,6 +259,10 @@ def schedule_levels(
     (:func:`repro.core.inspector.inspect_dependences`) — on top of the
     statement-level retained set.  Pass the affine retained set alongside
     them: the inspector is authoritative only for the indirect array set.
+    ``inactive`` names the instances the inspector resolved out (a guard over
+    a read-only array, not positive): they are laid out in no level, and
+    an order through one still holds between the live instances around it
+    (an inactive instance adds no level to a path).
 
     Per-dimension non-negative retained sets take the classic longest-path
     ISD layering below; sets with mixed-sign distance components route
@@ -283,6 +297,7 @@ def schedule_levels(
             scc_policy=scc_policy,
             level_cost=level_cost,
             instance_edges=instance_edges,
+            inactive=inactive,
         )
         return WavefrontSchedule(
             program=prog,
@@ -300,7 +315,8 @@ def schedule_levels(
     except ValueError as e:  # pragma: no cover - guarded above for deps
         raise WavefrontError(str(e)) from e
 
-    # Kahn layering: level(v) = 1 + max(level(pred)); cycle check for free.
+    # Kahn layering: level(v) = max(level(pred) + 1), +0 after an inactive
+    # pred; cycle check for free.
     nodes: List[Instance] = [
         (s.name, it) for it in prog.iterations() for s in prog.statements
     ]
@@ -321,13 +337,14 @@ def schedule_levels(
         nxt: List[Instance] = []
         for u in frontier:
             done += 1
+            after = level[u] + (u not in inactive)
             for v, _tag in isd.successors(u):
-                level[v] = max(level.get(v, 0), level[u] + 1)
+                level[v] = max(level.get(v, 0), after)
                 indeg[v] -= 1
                 if indeg[v] == 0:
                     nxt.append(v)
             for v in extra.get(u, ()):
-                level[v] = max(level.get(v, 0), level[u] + 1)
+                level[v] = max(level.get(v, 0), after)
                 indeg[v] -= 1
                 if indeg[v] == 0:
                     nxt.append(v)
@@ -340,13 +357,13 @@ def schedule_levels(
             "check the retained dependences for a cyclic Δ-sign mix"
         )
 
-    depth = max(level.values(), default=-1) + 1
+    live = [v for v in nodes if v not in inactive]  # iteration order
+    depth = max((level[v] for v in live), default=-1) + 1
     by_level: List[Dict[str, List[Tuple[int, ...]]]] = [
         {} for _ in range(depth)
     ]
-    for it in prog.iterations():  # iteration order → sorted group members
-        for s in prog.statements:
-            by_level[level[(s.name, it)]].setdefault(s.name, []).append(it)
+    for name, it in live:  # iteration order → sorted group members
+        by_level[level[(name, it)]].setdefault(name, []).append(it)
     return WavefrontSchedule(
         program=prog,
         levels=_levels_to_groups(prog, by_level),

@@ -196,6 +196,12 @@ class _Span:
         self.cat = cat
         self.args = args
 
+    def note(self, **args: Any) -> None:
+        """Add args known only by the span's end (counts of the work it
+        covered)."""
+
+        self.args.update(args)
+
     def __enter__(self) -> "_Span":
         _stack().append(self.name)
         factory = _annotate
@@ -227,6 +233,9 @@ class _NullSpan:
     """Shared do-nothing context manager for the disabled path."""
 
     __slots__ = ()
+
+    def note(self, **args: Any) -> None:
+        pass
 
     def __enter__(self) -> "_NullSpan":
         return self

@@ -16,6 +16,7 @@ Semantics stays with the sequential oracle: each deps mode on each
 registered backend must reproduce its store bit for bit.
 """
 
+import dataclasses
 import random
 
 import pytest
@@ -35,6 +36,7 @@ from repro.core import (
     execution_backends,
     gather_scatter,
     histogram,
+    index_content_digest,
     indexed_store,
     inspect_dependences,
     inspector_cache_stats,
@@ -44,6 +46,7 @@ from repro.core import (
     sparse_matvec,
     speculation_violations,
 )
+from repro.core.inspector import resolved_guard_arrays
 from repro.core.wavefront import schedule_levels
 
 MODES = (None, "inspect", "speculate")
@@ -53,30 +56,47 @@ MODES = (None, "inspect", "speculate")
 # Reference implementation: O(n²) pairwise subscript comparison
 # ---------------------------------------------------------------------- #
 
+def live_instances(prog, store):
+    """Every instance that can run: a guard over an array no statement
+    writes is evaluated (not positive: the instance never runs); any other
+    guard is conservatively taken as passing."""
+
+    written = {s.write.array for s in prog.statements}
+    out = []
+    for it in prog.iterations():
+        for s in prog.statements:
+            g = s.guard
+            if g is not None and g.array not in written:
+                if not store[g.array][ref_cell(g, it, store)] > 0:
+                    continue
+            out.append((s, it))
+    return out
+
+
 def brute_force_pairs(prog, store):
-    """All (earlier, later) instance pairs conflicting on an inspected
-    array — the quadratic reference the inspector's sweep must agree with.
-    Same conventions as the inspector: guards conservatively always read,
+    """All (earlier, later) pairs of live instances conflicting on an
+    inspected array — the quadratic reference the inspector's sweep must
+    agree with.  Same conventions as the inspector: guards over read-only
+    arrays evaluated, other guards conservatively always read,
     same-iteration pairs omitted (program order covers them)."""
 
     targets = set(inspect_dependences(prog, store).arrays)
     accesses = []  # (instance, frozenset of read cells, write cell or None)
-    for it in prog.iterations():
-        for s in prog.statements:
-            reads = list(s.reads)
-            if s.guard is not None:
-                reads.append(s.guard)
-            rcells = frozenset(
-                (r.array, ref_cell(r, it, store))
-                for r in reads
-                if r.array in targets
-            )
-            wcell = (
-                (s.write.array, ref_cell(s.write, it, store))
-                if s.write.array in targets
-                else None
-            )
-            accesses.append(((s.name, it), rcells, wcell))
+    for s, it in live_instances(prog, store):
+        reads = list(s.reads)
+        if s.guard is not None:
+            reads.append(s.guard)
+        rcells = frozenset(
+            (r.array, ref_cell(r, it, store))
+            for r in reads
+            if r.array in targets
+        )
+        wcell = (
+            (s.write.array, ref_cell(s.write, it, store))
+            if s.write.array in targets
+            else None
+        )
+        accesses.append(((s.name, it), rcells, wcell))
     pairs = set()
     for i, (u, ur, uw) in enumerate(accesses):
         for v, vr, vw in accesses[i + 1:]:
@@ -91,18 +111,22 @@ def brute_force_pairs(prog, store):
 
 
 def exact_schedule(prog, store):
-    """The deps="inspect" schedule: affine retained set + instance edges."""
+    """The deps="inspect" schedule: affine retained set + instance edges,
+    over the live instances."""
 
     p = plan(prog, PlanOptions(deps="inspect"))
+    insp = inspect_dependences(prog, store)
     return schedule_levels(
         prog,
         list(affine_retained(p.retained)),
-        instance_edges=inspect_dependences(prog, store).edges,
+        instance_edges=insp.edges,
+        inactive=insp.inactive,
     )
 
 
 def assert_graph_cross_checks(prog, store):
-    """Soundness + sufficiency of the inspector graph vs brute force."""
+    """Soundness + sufficiency of the inspector graph vs brute force, and
+    the schedule laying out exactly the live instances."""
 
     insp = inspect_dependences(prog, store)
     pairs = brute_force_pairs(prog, store)
@@ -113,6 +137,10 @@ def assert_graph_cross_checks(prog, store):
     assert not violated, (
         f"exact schedule breaks pairwise conflicts: {violated[:5]}"
     )
+    live = {(s.name, it) for s, it in live_instances(prog, store)}
+    assert set(sched.level_of()) == live
+    every = {(s.name, it) for it in prog.iterations() for s in prog.statements}
+    assert insp.inactive == every - live
 
 
 def assert_modes_bit_equal(prog, store=None, backends=None):
@@ -207,6 +235,142 @@ class TestGraphVsBruteForce:
         # exact depth equals the busiest bin's multiplicity
         depth = exact_schedule(prog, store).depth
         assert depth == max(bins.count(b) for b in set(bins))
+
+
+# ---------------------------------------------------------------------- #
+# Guards the inspector resolves (read-only guard arrays) and those it
+# cannot (a guard array the loop writes)
+# ---------------------------------------------------------------------- #
+
+def random_guarded(seed, n_iter=8):
+    """A seeded random non-affine program whose statements are guarded, at
+    random, by the read-only flag array ``g`` (contents ±1 from the seed)."""
+
+    prog, store = random_nonaffine(seed, n_iter)
+    rng = random.Random(seed + 7)
+    flags = [rng.random() < 0.6 for _ in prog.statements]
+    flags[rng.randrange(len(flags))] = True
+    prog = LoopProgram(
+        statements=tuple(
+            dataclasses.replace(s, guard=ArrayRef("g", 0)) if f else s
+            for s, f in zip(prog.statements, flags)
+        ),
+        bounds=prog.bounds,
+    )
+    g = {(i,): rng.choice((-1.0, 1.0)) for i in range(n_iter)}
+    return prog, dict(store, g=g)
+
+
+def guarded_histogram(n=8):
+    """``if (g[i] > 0) h[bin[i]] = f(h[bin[i]], w[i])``."""
+
+    h = IndirectRef("h", ArrayRef("bin", 0))
+    return LoopProgram(
+        statements=(
+            Statement("S1", h, (h, ArrayRef("w", 0)), guard=ArrayRef("g", 0)),
+        ),
+        bounds=((0, n),),
+    )
+
+
+class TestResolvedGuards:
+    @pytest.mark.parametrize("seed", range(12))
+    def test_live_graph_vs_brute_force(self, seed):
+        prog, store = random_guarded(seed)
+        assert resolved_guard_arrays(prog) == ("g",)
+        assert inspect_dependences(prog, store).inactive
+        assert_graph_cross_checks(prog, store)
+        assert_modes_bit_equal(prog, store, backends=("wavefront",))
+
+    @pytest.mark.parametrize("seed", (0, 5))
+    def test_live_graph_all_backends(self, seed):
+        prog, store = random_guarded(seed)
+        assert_modes_bit_equal(prog, store)
+
+    def test_guarded_out_instances_leave_every_level(self):
+        prog = guarded_histogram()
+        store = indexed_store(prog, {"bin": [2] * 8})
+        store["g"] = {(i,): 1.0 if i in (1, 6) else 0.0 for i in range(8)}
+        insp = inspect_dependences(prog, store)
+        assert insp.edges == ((("S1", (1,)), ("S1", (6,))),)
+        assert len(insp.inactive) == 6
+        sched = exact_schedule(prog, store)
+        assert sched.level_of() == {("S1", (1,)): 0, ("S1", (6,)): 1}
+        assert_modes_bit_equal(prog, store)
+
+    def test_an_inactive_instance_adds_no_level(self):
+        """Program order runs S1 → S2 → S3 in each iteration; with every S2
+        guarded out, S3 sits right after S1, and no level is left empty."""
+
+        prog = LoopProgram(
+            statements=(
+                Statement("S1", IndirectRef("a", ArrayRef("idx", 0)), ()),
+                Statement("S2", ArrayRef("b", 0), (), guard=ArrayRef("g", 0)),
+                Statement(
+                    "S3", ArrayRef("c", 0), (IndirectRef("a", ArrayRef("idx", 0)),)
+                ),
+            ),
+            bounds=((0, 8),),
+        )
+        store = indexed_store(prog, {"idx": list(range(8))})
+        store["g"] = {k: -1.0 for k in store["g"]}
+        sched = exact_schedule(prog, store)
+        assert sched.depth == 2
+        assert [sorted({g.statement for g in lv}) for lv in sched.levels] == [
+            ["S1"], ["S3"]
+        ]
+        assert_modes_bit_equal(prog, store)
+
+    def test_written_guard_array_stays_conservative(self):
+        # S2's guard reads b, which S1 writes: only the loop knows it
+        prog = LoopProgram(
+            statements=(
+                Statement(
+                    "S1",
+                    ArrayRef("b", 0),
+                    (IndirectRef("a", ArrayRef("idx", 0)),),
+                ),
+                Statement(
+                    "S2",
+                    IndirectRef("a", ArrayRef("perm", 0)),
+                    (ArrayRef("b", 0),),
+                    guard=ArrayRef("b", -1),
+                ),
+            ),
+            bounds=((0, 8),),
+        )
+        store = indexed_store(
+            prog, {"idx": [3, 1, 4, 1, 5, 2, 6, 5], "perm": [1, 2, 3, 4, 0, 5, 6, 7]}
+        )
+        store["b"] = {k: -1.0 for k in store["b"]}  # b[i-1] <= 0 at entry
+        assert resolved_guard_arrays(prog) == ()
+        insp = inspect_dependences(prog, store)
+        assert insp.inactive == frozenset()
+        assert exact_schedule(prog, store).instances == 16
+        assert_graph_cross_checks(prog, store)
+        assert_modes_bit_equal(prog, store)
+
+    def test_guard_contents_key_the_cases_and_the_memo(self):
+        """Equal index arrays, other guard contents: a case and an
+        inspection of their own, both bit-equal to the oracle."""
+
+        from repro.compile import clear_compile_cache
+
+        prog = guarded_histogram()
+        first = indexed_store(prog, {"bin": [0, 1, 0, 1, 0, 1, 0, 1]})
+        first["g"] = {(i,): (-1.0) ** i for i in range(8)}
+        second = dict(first, g={k: -v for k, v in first["g"].items()})
+        assert index_content_digest(prog, first) != index_content_digest(
+            prog, second
+        )
+        clear_compile_cache()
+        clear_inspector_cache()
+        ex = plan(prog, PlanOptions(deps="inspect")).compile("xla")
+        for store in (first, second, first):
+            init = {a: dict(c) for a, c in store.items()}
+            assert ex.run(store=init) == run_sequential(prog, init)
+        assert ex.compiled.prepared_cases == 2
+        assert inspector_cache_stats()["misses"] == 2
 
 
 # ---------------------------------------------------------------------- #

@@ -3,9 +3,11 @@
 Each test compiles for a *described* TPU v5e (``jax.experimental.topologies``)
 what ``chip_smoke.py`` runs on the chip: the level loop of every smoke
 program at its smoke size (``repro.workloads``), and the Pallas matmul at
-4096² in bf16.  Nothing runs, so results and times are not checked; what is
-checked is that XLA:TPU accepts the programs, and that the float64
-laundering of the lowering is emitted for XLA:CPU only.
+4096² in bf16.  Beside them, the level loop of the benchmark's HPCG SymGS
+configuration at a 4^3 grid: three statements, two of them guarded.
+Nothing runs, so results and times are not checked; what is checked is
+that XLA:TPU accepts the programs, and that the float64 laundering of the
+lowering is emitted for XLA:CPU only.
 
 The topology is described inside module-scoped fixtures, never at import:
 only one process at a time may load the TPU library, and the suite runs
@@ -90,16 +92,26 @@ def _lowered(compiled, case, sharding):
         )
 
 
-@pytest.mark.parametrize("name", SMOKE_PROGRAMS)
+def _program_and_store(name):
+    if name == "hpcg_symgs":
+        from test_hpcg_symgs import symgs
+
+        _mod, _cfg, prog, options, store = symgs()
+        return prog, options, store
+    prog, options = smoke_program(name)
+    return prog, options, seeded_store(prog, 0)
+
+
+@pytest.mark.parametrize("name", SMOKE_PROGRAMS + ("hpcg_symgs",))
 def test_level_loop_compiles_for_v5e(name, one_chip, no_persistent_cache):
     import jax
 
     from repro.core import plan
     from repro.core.wavefront import _DenseStore
 
-    prog, options = smoke_program(name)
+    prog, options, store = _program_and_store(name)
     compiled = plan(prog, options).compile("xla").compiled
-    case, _ = compiled.prepare(prog, _DenseStore(seeded_store(prog, 0)))
+    case, _ = compiled.prepare(prog, _DenseStore(store))
 
     tpu = _lowered(compiled, case, one_chip)
     cpu = _lowered(
