@@ -16,9 +16,10 @@ level loop into a single XLA computation instead:
     so a 1024-wide DOALL statement does not inflate a width-1 chain;
   * the executable is a ``lax.fori_loop`` over levels whose body keeps one
     cursor per statement: when the cursor's next group belongs to the
-    current level, a ``lax.cond`` runs that group's vectorized
-    gather/compute/scatter and advances the cursor.  Per level, only the
-    statements that actually have work pay for it.
+    current level, a ``lax.switch`` runs that group's vectorized
+    gather/compute/scatter at the narrowest lane cap that holds its live
+    lanes and advances the cursor.  Per level, only the statements that
+    actually have work pay for it, and only for the lanes of their cap.
 
 Because the tables are *data*, the group/lane axes are padded to
 power-of-two buckets, and every per-bounds scalar (level count, segment
@@ -73,6 +74,7 @@ later level reads).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -106,15 +108,26 @@ class XlaLoweringError(ValueError):
 from repro.compile import _next_pow2  # noqa: E402
 
 
-# Width ladder for recurrence bands (ROADMAP 3b).  A band's ramp-up and
-# ramp-down levels run at sliced lane widths — halvings of the padded band
-# width, at most WIDTH_LADDER_RUNGS of them, never narrower than
+# Width ladder (ROADMAP 3b, A5).  The level loop pays for every lane it
+# gathers, computes and scatters, live or padding, so each table row runs at
+# the narrowest lane cap that holds its live lanes: a recurrence band's rows
+# at the caps of its ramps, a wave row at its own.  The caps start at
 # WIDTH_LADDER_MIN lanes (below that the per-step dispatch cost dwarfs any
-# lane saving).  Read late (module attribute lookup, not captured values)
-# so benchmarks can pin ``lowering.WIDTH_LADDER_RUNGS = 0`` for an unsplit
-# control build.
-WIDTH_LADDER_RUNGS = 3
+# lane saving) and lie 4× apart up to the padded width, at most
+# WIDTH_LADDER_RUNGS of them below it, a bound that does not bind by
+# default.  Each cap compiles a group step of its own: halvings would
+# triple the steps a three-statement case traces.  Read late (module
+# attribute lookup, not captured values) so benchmarks can pin
+# ``lowering.WIDTH_LADDER_RUNGS = 0`` for an unsplit control build.
+WIDTH_LADDER_RUNGS = 32
 WIDTH_LADDER_MIN = 8
+
+
+def _ladder(width: int, rungs: int) -> List[int]:
+    """The ascending lane caps of a width ladder: the ``rungs`` narrowest
+    of ``WIDTH_LADDER_MIN``, 4×, 16×, … lanes, then ``width`` itself."""
+
+    return [WIDTH_LADDER_MIN << 2 * i for i in range(rungs)] + [width]
 
 
 # The chip's contract.  XLA:TPU emulates float64 with pairs of float32: on a
@@ -336,8 +349,12 @@ class _StmtStatic:
     # narrow statements run every level with the active bit folded into the
     # lane mask (a handful of trash-redirected lanes) — cheaper than a
     # lax.cond, whose pass-through copies the write array at every level;
-    # wide statements keep the cond so inactive levels don't pay their lanes
+    # wide statements keep the cond so inactive levels don't pay their
+    # lanes, and run an active row through a lax.switch over the ladder's
+    # caps, the row's ``rung`` column picking the narrowest that holds its
+    # live lanes
     use_cond: bool = True
+    lane_rungs: int = 0            # ladder caps below the full width
 
 
 @dataclasses.dataclass(frozen=True)
@@ -378,10 +395,12 @@ class PreparedCase:
     #   wave → [lo, hi, cursors0…] ; rec → [n_chunks, row0…]
     seg_dyn: Tuple[np.ndarray, ...] = ()
     bucket: Tuple = ()                          # trace-identity key (host view)
-    # lanes a run computes with the mask set, and lanes it computes in all
-    # (each statement's groups × its padded width): the xla.lanes_* counters
+    # lanes a run computes with the mask set, each statement's groups × its
+    # padded width, and the lanes a run computes at its rows' lane caps
+    # (see _lanes_computed): the xla.lanes_* counters
     lanes_live: int = 0
     lanes_padded: int = 0
+    lanes_computed: int = 0
     _device_tables: Optional[Tuple] = None      # jnp copies, converted once
     _device_segdyn: Optional[Tuple] = None
 
@@ -393,6 +412,45 @@ _HOLE_MSG = (
     "read of an uninitialized cell — the provided store does not cover "
     "this access"
 )
+
+
+def _lanes_computed(
+    stmts: Sequence[_StmtStatic],
+    segments: Optional[Tuple[Tuple, ...]],
+    seg_dyn: Tuple[np.ndarray, ...],
+    tables: Sequence[Dict[str, np.ndarray]],
+    n_levels: int,
+) -> int:
+    """Lanes one run of a case gathers, computes and scatters: a band row
+    at its rung's cap (no wider than its statement), a cond statement's
+    wave row at the cap its ``rung`` column names, and a condless statement
+    at its full width on every level outside the bands."""
+
+    wps = [t["lanemask"].shape[1] for t in tables]
+    in_band = [np.zeros(len(t["glevel"]), dtype=bool) for t in tables]
+    total, wave_levels = 0, n_levels
+    for seg, dyn in zip(segments or (), seg_dyn):
+        if seg[0] != "rec":
+            continue
+        stmt_ks = seg[1]
+        J, n = len(stmt_ks), int(dyn[0])
+        ws = _ladder(
+            max(wps[k] for k in stmt_ks), (dyn.shape[0] - 1 - J) // 2
+        )
+        edges = [0, *(int(e) for e in dyn[1 + J:]), n]
+        for lo, hi, cap in zip(edges, edges[1:], ws + ws[-2::-1]):
+            total += (hi - lo) * sum(min(cap, wps[k]) for k in stmt_ks)
+        for j, k in enumerate(stmt_ks):
+            in_band[k][int(dyn[1 + j]):int(dyn[1 + j]) + n] = True
+        wave_levels -= n
+    for k, (ss, t) in enumerate(zip(stmts, tables)):
+        if ss.use_cond:
+            live = (t["glevel"] < n_levels) & ~in_band[k]
+            caps = np.asarray(_ladder(wps[k], ss.lane_rungs), dtype=np.int64)
+            total += int(caps[t["rung"][live]].sum())
+        else:
+            total += wave_levels * wps[k]
+    return total
 
 
 class CompiledProgram:
@@ -827,6 +885,8 @@ class CompiledProgram:
                 s.guard is not None and s.guard.array in dense.mask
             )
             cov_write = s.write.array in dense.mask
+            use_cond = self._use_cond(Wp)
+            lane_rungs = self._band_rungs(Wp) if use_cond else 0
 
             stmt_statics.append(
                 _StmtStatic(
@@ -838,7 +898,8 @@ class CompiledProgram:
                     cov_reads=cov_reads,
                     cov_guard=cov_guard,
                     cov_write=cov_write,
-                    use_cond=self._use_cond(Wp),
+                    use_cond=use_cond,
+                    lane_rungs=lane_rungs,
                 )
             )
             table = {
@@ -853,6 +914,13 @@ class CompiledProgram:
                 table["gidx"] = idx["guard"]
             if has_oob:
                 table["oob"] = oob
+            if use_cond:
+                # each row's rung: its narrowest lane cap (sentinels: 0)
+                rung = np.zeros(Gp, dtype=np.int32)
+                rung[:G] = np.searchsorted(
+                    _ladder(Wp, lane_rungs), row_widths[-1]
+                )
+                table["rung"] = rung
             tables.append(table)
 
         # Segment hybrid schedules AND inspect schedules: the band detector
@@ -914,6 +982,9 @@ class CompiledProgram:
             bucket=bucket,
             lanes_live=sched.instances,
             lanes_padded=sum(len(w) * wp for w, wp in zip(row_widths, wps)),
+            lanes_computed=_lanes_computed(
+                static.stmts, segments, seg_dyn, tables, n_levels
+            ),
         )
 
     # Minimum run of uniform levels worth collapsing into a nested loop —
@@ -926,17 +997,18 @@ class CompiledProgram:
     MAX_BANDS = 4
 
     def _band_rungs(self, wpb: int) -> int:
-        """Width-ladder depth for a recurrence band of padded width
-        ``wpb``: the number of halvings (≤ ``WIDTH_LADDER_RUNGS``) whose
-        narrowest rung still holds ``WIDTH_LADDER_MIN`` lanes.  The sharded
-        artifact overrides this to 0 (its per-shard lane slicing needs the
-        full padded width).  Reads the module knobs late so a bench can
-        pin the ladder off for an unsplit control build."""
+        """Width-ladder depth for a recurrence band, or a wave statement,
+        of padded width ``wpb``: the number of caps of :func:`_ladder`
+        narrower than ``wpb`` (≤ ``WIDTH_LADDER_RUNGS``).  A function of the
+        padded width alone, so the ladder's shape never splits a bucket.
+        The sharded artifact overrides this to 0 (its per-shard lane
+        slicing needs the full padded width).  Reads the module knobs late
+        so a bench can pin the ladder off for an unsplit control build."""
 
         rungs = 0
         while (
             rungs < WIDTH_LADDER_RUNGS
-            and (wpb >> (rungs + 1)) >= WIDTH_LADDER_MIN
+            and (WIDTH_LADDER_MIN << 2 * rungs) < wpb
         ):
             rungs += 1
         return rungs
@@ -988,7 +1060,7 @@ class CompiledProgram:
                     for j, k in enumerate(stmt_ks)
                 )
 
-            ws = [wpb >> (rungs - i) for i in range(rungs)]
+            ws = _ladder(wpb, rungs)[:-1]
             cuts_p = []
             for w in ws:
                 p = cuts_p[-1] if cuts_p else 0  # prefixes are monotone
@@ -1125,9 +1197,10 @@ class CompiledProgram:
 
             ``lane_cap`` (a static int) restricts the step to the row's
             leading ``lane_cap`` lanes — the width-ladder rungs of a
-            recurrence band's ramps use it to skip gathers/scatters on
-            lanes that are provably padding there (mask-false, so skipping
-            them is structural, not a numerical approximation)."""
+            recurrence band's ramps and of a wave statement's switch use
+            it to skip gathers/scatters on lanes that are provably padding
+            there (mask-false, so skipping them is structural, not a
+            numerical approximation)."""
 
             t = tables[k]
 
@@ -1180,11 +1253,13 @@ class CompiledProgram:
                     == level
                 )
 
-                # the cond returns only what the group writes (one array,
-                # optionally its coverage, the flags)
-                def run_group(k=k, ss=ss, c=c, bad=bad, store=store,
+                # the branches return only what the group writes (one
+                # array, optionally its coverage, the flags)
+                def run_group(cap, k=k, ss=ss, c=c, bad=bad, store=store,
                               coverage=coverage):
-                    return group_step(k, ss, c, store, coverage, bad)
+                    return group_step(
+                        k, ss, c, store, coverage, bad, lane_cap=cap
+                    )
 
                 def skip_group(ss=ss, bad=bad, store=store,
                                coverage=coverage):
@@ -1195,8 +1270,26 @@ class CompiledProgram:
                     )
 
                 if ss.use_cond:
+                    # an active row runs at its rung's lane cap, the
+                    # narrowest that holds its live lanes.  The switch sits
+                    # inside the cond: a flat switch over [skip, caps…]
+                    # costs an idle level more than a two-way cond does
+                    width = tables[k]["lanemask"].shape[1]
+                    runs = [
+                        functools.partial(
+                            run_group, cap if cap < width else None
+                        )
+                        for cap in _ladder(width, ss.lane_rungs)
+                    ]
+
+                    def run_capped(k=k, c=c, runs=runs):
+                        rung = lax.dynamic_index_in_dim(
+                            tables[k]["rung"], c, axis=0, keepdims=False
+                        )
+                        return lax.switch(rung, runs)
+
                     new_write, new_cov, bad = lax.cond(
-                        active, run_group, skip_group
+                        active, run_capped, skip_group
                     )
                 else:
                     new_write, new_cov, bad = group_step(
@@ -1276,8 +1369,8 @@ class CompiledProgram:
                     wpb = max(
                         tables[k]["lanemask"].shape[1] for k in stmt_ks
                     )
-                    ws = [wpb >> (L - i) for i in range(L)]
-                    caps = ws + [wpb] + list(reversed(ws))
+                    ws = _ladder(wpb, L)
+                    caps = ws + ws[-2::-1]
                     edges = (
                         [0]
                         + [dyn[1 + J + i] for i in range(2 * L)]
@@ -1365,6 +1458,7 @@ class CompiledProgram:
         _metrics.counter("xla.levels").inc(case.n_levels)
         _metrics.counter("xla.lanes_live").inc(case.lanes_live)
         _metrics.counter("xla.lanes_padded").inc(case.lanes_padded)
+        _metrics.counter("xla.lanes_computed").inc(case.lanes_computed)
 
         with x64():
             with _trace.span("xla.to_device"):

@@ -92,7 +92,10 @@ def test_ladder_cuts_are_monotone_and_fit_the_ramp():
     (seg,) = [s for s in case.static.segments if s[0] == "rec"]
     n_stmts = len(seg[1])
     L = (dyn.shape[0] - 1 - n_stmts) // 2
-    assert L == lowering.WIDTH_LADDER_RUNGS  # wide enough for a full ladder
+    wpb = max(case.tables[k]["lanemask"].shape[1] for k in seg[1])
+    # the full ladder: every cap from WIDTH_LADDER_MIN lanes up
+    assert L == rep.compiled._band_rungs(wpb) > 0
+    assert lowering._ladder(wpb, L)[0] == lowering.WIDTH_LADDER_MIN
     n = int(dyn[0])
     cuts = [int(c) for c in dyn[1 + n_stmts:]]
     # monotone: 0 <= P_1 <= ... <= P_L <= Q_L <= ... <= Q_1 <= n
@@ -187,8 +190,8 @@ def test_spmd_pins_the_ladder_off():
     from repro.compile.spmd import SpmdCompiledProgram
 
     assert SpmdCompiledProgram._band_rungs(object(), 4096) == 0
-    # the base artifact ladders the same width
-    assert lowering.CompiledProgram._band_rungs(object(), 4096) == 3
+    # the base artifact ladders the same width: caps 8, 32, 128, 512, 2048
+    assert lowering.CompiledProgram._band_rungs(object(), 4096) == 5
 
 
 def test_lane_cap_never_exceeds_statement_width():
@@ -206,5 +209,5 @@ def test_lane_cap_never_exceeds_statement_width():
         wpb = max(
             case.tables[k]["lanemask"].shape[1] for k in seg[1]
         )
-        for i in range(L):
-            assert wpb >> (L - i) >= lowering.WIDTH_LADDER_MIN
+        for cap in lowering._ladder(wpb, L):
+            assert lowering.WIDTH_LADDER_MIN <= cap <= wpb
